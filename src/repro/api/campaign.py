@@ -354,7 +354,9 @@ class Campaign:
 
         ``workers=N`` fans uncached scenarios over N spawn processes (each
         runs against a snapshot of the campaign DB; insert deltas merge
-        back as runs complete).  Serial sweeps on batch-capable engines
+        back as runs complete).  Backends that run on the accelerator
+        (``fluid``, ``learned``) refuse ``workers > 1`` with a
+        ``ValueError``: one process holds the accelerator.  Serial sweeps on batch-capable engines
         (fluid's padded vmap) keep their batched evaluation.
 
         ``store=URL`` attaches the campaign to a shared store server (see
@@ -368,6 +370,12 @@ class Campaign:
         reclaimed."""
         scenarios = list(scenarios)
         engine = get_engine(backend)
+        if workers > 1 and engine.on_device:
+            raise ValueError(
+                f"backend {backend!r} runs on the accelerator, which only "
+                f"one process can hold: workers={workers} would start spawn "
+                f"workers that need it while this process may hold it — "
+                f"sweep it with workers=1 (it batches on the device)")
         if store is not None:
             self._attach_store(store)
         engine.check_opts(opts)

@@ -67,6 +67,10 @@ class Engine:
     the seam campaigns use to thread their memo DB through a backend
     without hard-coding backend names.
 
+    ``on_device = True`` declares that ``run`` puts work on the JAX
+    accelerator.  One process holds an accelerator, so campaigns refuse to
+    fan such a backend out over spawn workers.
+
     ``option_names`` declares the opts ``run`` accepts; the API layer
     (``Campaign.submit``/``sweep``/``compare`` and the CLI) rejects
     anything else through :meth:`check_opts` with one shared error naming
@@ -76,6 +80,7 @@ class Engine:
     that have not declared their opts keep working unchecked)."""
     name = "abstract"
     uses_db = False
+    on_device = False
     option_names: tuple[str, ...] | None = None
 
     def check_opts(self, opts: dict) -> None:
@@ -319,6 +324,7 @@ class FluidEngine(Engine):
     cheaper, and ``run_batch`` evaluates a whole padded sweep in one
     vmapped compilation (§6.1 multi-experiment parallelism)."""
     option_names = ("dt", "steps")
+    on_device = True
 
     def run(self, scenario: Scenario, steps: int = 200, dt: float | None = None,
             **opts) -> RunResult:

@@ -60,9 +60,9 @@ def run_many(scenarios: list[Scenario], backend: str = "packet",
     fans the scenarios out over N processes; results keep scenario order,
     and each scenario is evaluated exactly as a standalone ``run()`` —
     identical to the serial path for per-scenario engines
-    (packet/wormhole/analytic are deterministic), while batching engines
-    (fluid's padded vmap, which also shares one ``dt`` across the batch)
-    use their per-scenario path instead.  With a DB, every worker starts
+    (packet/wormhole/analytic are deterministic).  The device engines
+    (fluid, learned) refuse ``workers > 1``: one process holds the
+    accelerator, and they batch a sweep on it instead.  With a DB, every worker starts
     from the same initial snapshot (no mid-sweep warm-up, unlike the
     serial path) and the parent merges every worker's insert delta back,
     deduplicating transients memoized by more than one worker — a cold

@@ -1,6 +1,7 @@
-"""jit'd public wrapper: pads (flows, links) to kernel tile multiples,
-dispatches the Pallas kernel, unpads.  On non-TPU backends the kernel runs
-in interpret mode (CPU validation); on TPU set interpret=False."""
+"""jit'd public wrapper: pads (flows, links) to kernel tile multiples, lays
+the state out as the kernel's rows/columns, dispatches, unpads.  With
+``interpret=None`` the kernel compiles on a TPU backend and runs in
+interpret mode elsewhere (CPU validation)."""
 from __future__ import annotations
 
 from functools import partial
@@ -10,7 +11,7 @@ import jax.numpy as jnp
 
 from repro.kernels.cca_step.kernel import BF, cca_step_padded
 
-_LANES = 128
+_SUBLANES = 8   # link-axis pad multiple (float32 sublane tile)
 
 
 def _pad_to(x, n, fill=0.0):
@@ -27,18 +28,17 @@ def cca_step(R, W, alpha, delivered, size, line, rtt0, M, q, bw, *,
         interpret = jax.default_backend() != "tpu"
     F, L = M.shape
     Fp = -(-F // BF) * BF
-    Lp = -(-L // _LANES) * _LANES
+    Lp = -(-L // _SUBLANES) * _SUBLANES
     f32 = lambda x: jnp.asarray(x, jnp.float32)
-    Mp = jnp.pad(f32(M), ((0, Fp - F), (0, Lp - L)))
+    row = lambda x, fill=0.0: _pad_to(f32(x), Fp, fill)[None, :]
+    col = lambda x, fill=0.0: _pad_to(f32(x), Lp, fill)[:, None]
     args = (
-        _pad_to(f32(R), Fp), _pad_to(f32(W), Fp, fill=mss),
-        _pad_to(f32(alpha), Fp), _pad_to(f32(delivered), Fp),
-        _pad_to(f32(size), Fp),                # padded flows: size 0 -> idle
-        _pad_to(f32(line), Fp, fill=1.0),
-        _pad_to(f32(rtt0), Fp, fill=1.0),
-        Mp,
-        _pad_to(f32(q), Lp), _pad_to(f32(bw), Lp, fill=1.0),
+        row(R), row(W, fill=mss), row(alpha), row(delivered),
+        row(size),                             # padded flows: size 0 -> idle
+        row(line, fill=1.0), row(rtt0, fill=1.0),
+        jnp.pad(f32(M), ((0, Fp - F), (0, Lp - L))).T,
+        col(q), col(bw, fill=1.0),
     )
     R2, W2, a2, d2, arr = cca_step_padded(
         *args, dt=dt, g=g, ecn_k=ecn_k, mss=mss, interpret=interpret)
-    return R2[:F], W2[:F], a2[:F], d2[:F], arr[:L]
+    return R2[0, :F], W2[0, :F], a2[0, :F], d2[0, :F], arr[:L, 0]

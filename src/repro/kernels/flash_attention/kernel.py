@@ -72,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "scale", "interpret", "s_valid"))
 def flash_attention_padded(q, k, v, *, causal: bool, window: int | None,
-                           scale: float, s_valid: int, interpret: bool = True):
+                           scale: float, s_valid: int, interpret: bool):
     """q: [BH, S, D] flattened (batch·q_heads); k/v: [BHk, S, D] flattened
     (batch·kv_heads); requires S % BQ == 0 == S % BK and knowledge of the
     head grouping encoded by the caller in the index mapping."""

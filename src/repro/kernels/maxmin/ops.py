@@ -188,7 +188,9 @@ def maxmin_rates_jax(path_links, path_off, link_bw, *, impl: str = "ref",
                      interpret: bool | None = None) -> np.ndarray:
     """Fixed-point max-min via the jax ref (``impl="ref"``) or the Pallas
     kernel (``impl="kernel"``).  float32 — approximate parity with the
-    exact solver (≲1e-4 rel), exact parity kernel↔ref."""
+    exact solver (≲1e-4 rel), exact parity kernel↔ref (on CPU in
+    interpret mode and on a TPU v5e).  The kernel raises ``ValueError``
+    for an incidence over its VMEM budget."""
     SOLVER_COUNTERS["invocations"] += 1
     F = len(path_off) - 1
     if F > SOLVER_COUNTERS["max_flows"]:

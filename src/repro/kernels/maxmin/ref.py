@@ -20,6 +20,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
+
+compile_cache.enable()
+
 BIG = 3e38                   # sentinel share for user-less links
 NOLINK_RATE = 1e12           # rate for flows that cross no link (dict parity)
 

@@ -26,4 +26,4 @@ def steady_scan(hist, window: int, atol: float = 0.0,
     histp = jnp.pad(hist, ((0, Fp - F), (0, 0)), constant_values=pad_val)
     fluct, mean = steady_scan_padded(histp, window=window, atol=atol,
                                      interpret=interpret)
-    return fluct[:F], mean[:F]
+    return fluct[:F, 0], mean[:F, 0]

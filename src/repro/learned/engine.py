@@ -146,6 +146,7 @@ class LearnedEngine(Engine):
     which is what the OOD guard is for.
     """
     option_names = ("ood", "params")
+    on_device = True
 
     def run(self, scenario: Scenario, **opts) -> RunResult:
         return self.run_batch([scenario], **opts)[0]

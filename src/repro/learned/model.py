@@ -24,6 +24,8 @@ from hashlib import sha256
 
 import numpy as np
 
+from repro import compile_cache
+
 PARAMS_VERSION = 1
 
 
@@ -35,6 +37,7 @@ def init(seed: int, d_in: int, hidden: tuple[int, ...] = (64, 64)) -> list:
     """Fresh weight list ``[(W, b), ...]`` for ``d_in`` features."""
     import jax
     import jax.numpy as jnp
+    compile_cache.enable()
     sizes = (d_in, *hidden, 1)
     key = jax.random.PRNGKey(seed)
     weights = []
@@ -164,6 +167,7 @@ def predict(params: LearnedParams, X: np.ndarray) -> np.ndarray:
     moments and apply the network.  One call evaluates any batch size —
     the engine flattens whole scenario sweeps into a single invocation."""
     import jax.numpy as jnp
+    compile_cache.enable()
     mu = np.asarray(params.meta["mu"], np.float64)
     sigma = np.asarray(params.meta["sigma"], np.float64)
     xs = (np.asarray(X, np.float64) - mu) / sigma

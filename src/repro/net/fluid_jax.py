@@ -24,7 +24,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.net.topology import Topology
+
+compile_cache.enable()
+
+# f32-exact MXU passes: the TPU's default precision rounds matmul operands
+# to bf16, which the rates and queue delays here cannot afford
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass
@@ -78,7 +85,7 @@ def fluid_run(M, line, rtt0, size, bw, dt: float, steps: int,
                 dt=dt, g=g, ecn_k=ecn_k, mss=mss)
         else:
             p_l = jnp.clip((q - ecn_k) / (2 * ecn_k), 0.0, 1.0)
-            qd = (q / bw) @ M.T                       # [F] queue delay
+            qd = jnp.matmul(q / bw, M.T, precision=_EXACT)  # [F] queue delay
             rtt = rtt0 + qd
             p_f = jnp.max(M * p_l[None, :], axis=1)    # worst hop marks
             dtn = dt / rtt                             # round-trips this step
@@ -89,7 +96,7 @@ def fluid_run(M, line, rtt0, size, bw, dt: float, steps: int,
             active = delivered < size
             R2 = jnp.where(active, jnp.minimum(W2 / rtt, line), 0.0)
             delivered2 = jnp.minimum(delivered + R2 * dt, size)
-            arrivals = R2 @ M                          # [L] MXU matmul
+            arrivals = jnp.matmul(R2, M, precision=_EXACT)  # [L] MXU matmul
         q2 = jnp.clip(q + (arrivals - bw) * dt, 0.0, 64 * ecn_k)
         return (R2, W2, alpha2, delivered2, q2), (R2, q2)
 

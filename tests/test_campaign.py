@@ -377,6 +377,18 @@ def test_parallel_sweep_resume_with_workers(tmp_path):
     camp2.close()
 
 
+@pytest.mark.parametrize("backend", ["fluid", "learned"])
+def test_device_backend_sweep_refuses_workers(backend):
+    """A backend that runs on the accelerator is never fanned out over
+    spawn workers: the parent may hold the device the children need."""
+    with Campaign.in_memory(name="one-device") as camp:
+        events = []
+        camp.subscribe(events.append)
+        with pytest.raises(ValueError, match="one process"):
+            camp.sweep([flows_scenario()], backend=backend, workers=2)
+        assert events == [] and len(camp) == 0
+
+
 # --------------------------------------------------------------------- #
 # CLI (python -m repro) over the same API
 # --------------------------------------------------------------------- #

@@ -61,12 +61,13 @@ def test_small_mesh_dryrun_lowers_and_compiles():
     r = run_py("""
         import jax, jax.numpy as jnp
         from repro.configs.registry import ARCHS
+        from repro.launch.mesh import make_debug_mesh
         from repro.models.api import build_model
         from repro.parallel.sharding import rules_for, tree_shardings, batch_shardings
         from repro.train import optimizer as O
         cfg = ARCHS["granite-3-2b"].reduced()
         model = build_model(cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_debug_mesh(8, model=2)
         rules = rules_for(cfg, mesh, "train")
         ps = model.param_structs()
         psh = tree_shardings(model.param_axes(), ps, rules, mesh)
