@@ -13,6 +13,11 @@ the same structured :class:`RunResult`, so fidelity is a one-word knob:
               what-if queries at thousands of scenarios/sec, in-distribution
 
 Third-party backends register with ``@register_engine("name")``.
+
+Each built-in ``run`` records three spans (``repro.spans``):
+``repro.api.build`` from entry to its timed loop, ``repro.engine.loop``
+over exactly the interval ``RunResult.wall_time`` measures, and
+``repro.api.collect`` around the result's assembly.
 """
 from __future__ import annotations
 
@@ -28,6 +33,7 @@ from repro.net import chaos as chaos_mod
 from repro.net.hybrid_sim import FIDELITIES, HybridConfig, HybridKernel, HybridSim
 from repro.net.packet_sim import PacketSim
 from repro.net.sharded_sim import ShardedPacketSim
+from repro.spans import span
 from repro.workload.driver import WorkloadDriver
 
 # repro.net.fluid_jax (and with it jax) is imported lazily by FluidEngine:
@@ -124,25 +130,26 @@ def _drive(scenario: Scenario, sim) -> "WorkloadDriver | None":
 def _collect(backend: str, scenario: Scenario, sim, driver, wall: float,
              kernel_report: dict | None = None,
              record_rtt=()) -> RunResult:
-    if driver is not None:
-        assert driver.finished, f"{scenario.name}: program did not finish"
-        iteration = driver.iteration_time
-    elif sim.results:
-        iteration = (max(r.finish for r in sim.results.values())
-                     - min(r.start for r in sim.results.values()))
-    else:
-        iteration = None
-    extras = {}
-    if record_rtt:
-        extras["rtt_samples"] = {fid: list(sim.flows[fid].rtt_samples)
-                                 for fid in record_rtt}
-    return RunResult(
-        backend=backend, scenario=scenario.name,
-        fcts={fid: r.fct for fid, r in sim.results.items()},
-        flow_bytes={fid: r.bytes for fid, r in sim.results.items()},
-        tags={fid: r.tag for fid, r in sim.results.items()},
-        iteration_time=iteration, events_processed=sim.events_processed,
-        wall_time=wall, kernel_report=kernel_report, extras=extras)
+    with span("repro.api.collect"):
+        if driver is not None:
+            assert driver.finished, f"{scenario.name}: program did not finish"
+            iteration = driver.iteration_time
+        elif sim.results:
+            iteration = (max(r.finish for r in sim.results.values())
+                         - min(r.start for r in sim.results.values()))
+        else:
+            iteration = None
+        extras = {}
+        if record_rtt:
+            extras["rtt_samples"] = {fid: list(sim.flows[fid].rtt_samples)
+                                     for fid in record_rtt}
+        return RunResult(
+            backend=backend, scenario=scenario.name,
+            fcts={fid: r.fct for fid, r in sim.results.items()},
+            flow_bytes={fid: r.bytes for fid, r in sim.results.items()},
+            tags={fid: r.tag for fid, r in sim.results.items()},
+            iteration_time=iteration, events_processed=sim.events_processed,
+            wall_time=wall, kernel_report=kernel_report, extras=extras)
 
 
 @register_engine("packet")
@@ -166,35 +173,37 @@ class PacketEngine(Engine):
     def run(self, scenario: Scenario, record_rtt=(), until: float = float("inf"),
             parallel: str | None = None, intra_workers: int = 1,
             validate: bool = False, **opts) -> RunResult:
-        plan = chaos_mod.plan_for(scenario)
-        chaos_mod.check_backend(plan, self.name, intra_workers=intra_workers)
-        topo = scenario.build_topology()
-        kernel, report_fn = self._make_kernel(scenario, **opts)
-        if parallel is None or parallel == "none":
-            if intra_workers > 1 or validate:
-                # silently running the serial loop would make the user
-                # believe the fan-out (or invariant checking) was active
+        with span("repro.api.build"):
+            plan = chaos_mod.plan_for(scenario)
+            chaos_mod.check_backend(plan, self.name, intra_workers=intra_workers)
+            topo = scenario.build_topology()
+            kernel, report_fn = self._make_kernel(scenario, **opts)
+            if parallel is None or parallel == "none":
+                if intra_workers > 1 or validate:
+                    # silently running the serial loop would make the user
+                    # believe the fan-out (or invariant checking) was active
+                    raise ValueError(
+                        "intra_workers/validate require parallel='partitions'")
+                sim = PacketSim(topo, kernel=kernel, **scenario.sim)
+            elif parallel == "partitions":
+                sim = ShardedPacketSim(topo, kernel=kernel,
+                                       intra_workers=intra_workers,
+                                       validate=validate, **scenario.sim)
+            else:
                 raise ValueError(
-                    "intra_workers/validate require parallel='partitions'")
-            sim = PacketSim(topo, kernel=kernel, **scenario.sim)
-        elif parallel == "partitions":
-            sim = ShardedPacketSim(topo, kernel=kernel,
-                                   intra_workers=intra_workers,
-                                   validate=validate, **scenario.sim)
-        else:
-            raise ValueError(
-                f"unknown parallel mode {parallel!r} (use 'partitions')")
-        sim.record_rtt_fids = set(record_rtt)
-        driver = _drive(scenario, sim)
-        if plan is not None and plan.has_link_events:
-            plan.install(sim)
-        t0 = time.perf_counter()
-        try:
-            sim.run(until=until)
-        finally:
-            if parallel == "partitions":
-                sim.close()
-        wall = time.perf_counter() - t0
+                    f"unknown parallel mode {parallel!r} (use 'partitions')")
+            sim.record_rtt_fids = set(record_rtt)
+            driver = _drive(scenario, sim)
+            if plan is not None and plan.has_link_events:
+                plan.install(sim)
+        with span("repro.engine.loop"):
+            t0 = time.perf_counter()
+            try:
+                sim.run(until=until)
+            finally:
+                if parallel == "partitions":
+                    sim.close()
+            wall = time.perf_counter() - t0
         result = _collect(self.name, scenario, sim, driver, wall,
                           kernel_report=report_fn() if report_fn else None,
                           record_rtt=record_rtt)
@@ -273,38 +282,40 @@ class HybridEngine(Engine):
             record_rtt=(), until: float = float("inf"),
             intra_workers: int = 1, validate: bool = False,
             **opts) -> RunResult:
-        if isinstance(config, HybridConfig):
-            cfg = dataclasses.replace(config)    # never mutate the caller's
-        else:
-            cfg = HybridConfig.from_knobs({**scenario.kernel, **(config or {})})
-        # explicit engine opts override the config; an unset opt must not
-        # clobber a fidelity carried by config=/scenario.kernel
-        if fidelity is not None:
-            cfg.fidelity = fidelity
-        if demote_after is not None:
-            cfg.demote_after = demote_after
-        if cfg.fidelity not in FIDELITIES:
-            raise ValueError(f"unknown fidelity {cfg.fidelity!r}; "
-                             f"have {FIDELITIES}")
-        plan = chaos_mod.plan_for(scenario)
-        chaos_mod.check_backend(plan, self.name, intra_workers=intra_workers)
-        topo = scenario.build_topology()
-        kernel, report_fn = None, None
-        if cfg.fidelity != "packet":
-            kernel = HybridKernel(cfg)
-            report_fn = kernel.report
-        sim = HybridSim(topo, kernel=kernel, intra_workers=intra_workers,
-                        validate=validate, **scenario.sim)
-        sim.record_rtt_fids = set(record_rtt)
-        driver = _drive(scenario, sim)
-        if plan is not None and plan.has_link_events:
-            plan.install(sim)
-        t0 = time.perf_counter()
-        try:
-            sim.run(until=until)
-        finally:
-            sim.close()
-        wall = time.perf_counter() - t0
+        with span("repro.api.build"):
+            if isinstance(config, HybridConfig):
+                cfg = dataclasses.replace(config)    # never mutate the caller's
+            else:
+                cfg = HybridConfig.from_knobs({**scenario.kernel, **(config or {})})
+            # explicit engine opts override the config; an unset opt must not
+            # clobber a fidelity carried by config=/scenario.kernel
+            if fidelity is not None:
+                cfg.fidelity = fidelity
+            if demote_after is not None:
+                cfg.demote_after = demote_after
+            if cfg.fidelity not in FIDELITIES:
+                raise ValueError(f"unknown fidelity {cfg.fidelity!r}; "
+                                 f"have {FIDELITIES}")
+            plan = chaos_mod.plan_for(scenario)
+            chaos_mod.check_backend(plan, self.name, intra_workers=intra_workers)
+            topo = scenario.build_topology()
+            kernel, report_fn = None, None
+            if cfg.fidelity != "packet":
+                kernel = HybridKernel(cfg)
+                report_fn = kernel.report
+            sim = HybridSim(topo, kernel=kernel, intra_workers=intra_workers,
+                            validate=validate, **scenario.sim)
+            sim.record_rtt_fids = set(record_rtt)
+            driver = _drive(scenario, sim)
+            if plan is not None and plan.has_link_events:
+                plan.install(sim)
+        with span("repro.engine.loop"):
+            t0 = time.perf_counter()
+            try:
+                sim.run(until=until)
+            finally:
+                sim.close()
+            wall = time.perf_counter() - t0
         result = _collect(self.name, scenario, sim, driver, wall,
                           kernel_report=report_fn() if report_fn else None,
                           record_rtt=record_rtt)
@@ -319,48 +330,57 @@ class HybridEngine(Engine):
 @register_engine("fluid")
 class FluidEngine(Engine):
     """DCTCP-form fluid dynamics: per-phase converged rates turn into FCT
-    estimates; the phase DAG is scheduled analytically on top.  Coarser
-    than the oracle (~10-20% FCT error) but three orders of magnitude
-    cheaper, and ``run_batch`` evaluates a whole padded sweep in one
-    vmapped compilation (§6.1 multi-experiment parallelism)."""
+    estimates; the phase DAG is scheduled analytically on top.  Coarse: on
+    the 128-GPU GPT row (flow scale 1/256) its mean FCT error against the
+    packet oracle is 54.8%.  Cheap: there an iteration takes about 0.13 s
+    on a TPU v5e host, the packet oracle 5.8 s; ``run_batch`` evaluates a
+    whole padded sweep in one vmapped compilation (§6.1 multi-experiment
+    parallelism).
+
+    Each phase's ``FluidScenario.from_flows`` is the ``repro.fluid.build``
+    span; ``fluid_converged_rates`` records the rest of the host path."""
     option_names = ("dt", "steps")
     on_device = True
 
     def run(self, scenario: Scenario, steps: int = 200, dt: float | None = None,
             **opts) -> RunResult:
         from repro.net.fluid_jax import FluidScenario, fluid_converged_rates
-        chaos_mod.check_backend(chaos_mod.plan_for(scenario), self.name)
-        topo = scenario.build_topology()
-        phases = scenario.build_phases()
-        t0 = time.perf_counter()
-        fcts: dict[int, float] = {}
-        flow_bytes: dict[int, float] = {}
-        tags: dict[int, str] = {}
-        done_t: list[float] = [0.0] * len(phases)
-        total_steps = 0
-        for i, ph in enumerate(phases):
-            start = max((done_t[d] for d in set(ph.deps)), default=0.0) + ph.compute
-            if scenario.kind == "flows":
-                start += ph.flows[0].start if ph.flows else 0.0
-            end = start
-            if ph.flows:
-                fs = FluidScenario.from_flows(
-                    topo, [(f.fid, f.src, f.dst, f.size) for f in ph.flows])
-                rates = fluid_converged_rates(fs, steps=steps, dt=dt)["rates"]
-                total_steps += steps
-                for f, rate in zip(ph.flows, rates):
-                    fct = f.size / max(float(rate), 1e3)
-                    fcts[f.fid] = fct
-                    flow_bytes[f.fid] = f.size
-                    tags[f.fid] = f.tag
-                    end = max(end, start + fct)
-            done_t[i] = end
-        wall = time.perf_counter() - t0
-        iteration = max(done_t) if done_t else None
-        return RunResult(backend=self.name, scenario=scenario.name,
-                         fcts=fcts, flow_bytes=flow_bytes, tags=tags,
-                         iteration_time=iteration, events_processed=total_steps,
-                         wall_time=wall)
+        with span("repro.api.build"):
+            chaos_mod.check_backend(chaos_mod.plan_for(scenario), self.name)
+            topo = scenario.build_topology()
+            phases = scenario.build_phases()
+        with span("repro.engine.loop"):
+            t0 = time.perf_counter()
+            fcts: dict[int, float] = {}
+            flow_bytes: dict[int, float] = {}
+            tags: dict[int, str] = {}
+            done_t: list[float] = [0.0] * len(phases)
+            total_steps = 0
+            for i, ph in enumerate(phases):
+                start = max((done_t[d] for d in set(ph.deps)), default=0.0) + ph.compute
+                if scenario.kind == "flows":
+                    start += ph.flows[0].start if ph.flows else 0.0
+                end = start
+                if ph.flows:
+                    with span("repro.fluid.build"):
+                        fs = FluidScenario.from_flows(
+                            topo, [(f.fid, f.src, f.dst, f.size) for f in ph.flows])
+                    rates = fluid_converged_rates(fs, steps=steps, dt=dt)["rates"]
+                    total_steps += steps
+                    for f, rate in zip(ph.flows, rates):
+                        fct = f.size / max(float(rate), 1e3)
+                        fcts[f.fid] = fct
+                        flow_bytes[f.fid] = f.size
+                        tags[f.fid] = f.tag
+                        end = max(end, start + fct)
+                done_t[i] = end
+            wall = time.perf_counter() - t0
+        with span("repro.api.collect"):
+            iteration = max(done_t) if done_t else None
+            return RunResult(backend=self.name, scenario=scenario.name,
+                             fcts=fcts, flow_bytes=flow_bytes, tags=tags,
+                             iteration_time=iteration, events_processed=total_steps,
+                             wall_time=wall)
 
     def run_batch(self, scenarios: list[Scenario], steps: int = 200,
                   dt: float | None = None, **opts) -> list[RunResult]:
@@ -408,12 +428,14 @@ class AnalyticEngine(Engine):
 
     def run(self, scenario: Scenario, until: float = float("inf"),
             **opts) -> RunResult:
-        chaos_mod.check_backend(chaos_mod.plan_for(scenario), self.name)
-        sim = AnalyticSim(scenario.build_topology())
-        driver = _drive(scenario, sim)
-        t0 = time.perf_counter()
-        sim.run(until=until)
-        wall = time.perf_counter() - t0
+        with span("repro.api.build"):
+            chaos_mod.check_backend(chaos_mod.plan_for(scenario), self.name)
+            sim = AnalyticSim(scenario.build_topology())
+            driver = _drive(scenario, sim)
+        with span("repro.engine.loop"):
+            t0 = time.perf_counter()
+            sim.run(until=until)
+            wall = time.perf_counter() - t0
         return _collect(self.name, scenario, sim, driver, wall)
 
 

@@ -35,15 +35,17 @@ from repro.api.engines import get_engine
 from repro.api.results import Comparison, RunResult
 from repro.api.scenario import Scenario
 from repro.core.memo import SimDB
+from repro.spans import span
 
 __all__ = ["Comparison", "compare", "run", "run_many"]
 
 
 def run(scenario: Scenario, backend: str = "packet", **opts) -> RunResult:
     """Evaluate one scenario on one backend (an anonymous single-run
-    campaign underneath)."""
-    return Campaign.in_memory().submit(scenario, backend=backend,
-                                       **opts).result
+    campaign underneath); the ``repro.run`` span covers the whole call."""
+    with span("repro.run"):
+        return Campaign.in_memory().submit(scenario, backend=backend,
+                                           **opts).result
 
 
 def run_many(scenarios: list[Scenario], backend: str = "packet",

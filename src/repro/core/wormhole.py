@@ -25,6 +25,7 @@ from repro.core.memo import MemoEntry, MemoHit, SimDB, sim_fingerprint
 from repro.core.partition import PartitionIndex
 from repro.core.steady import is_steady, rate_estimate
 from repro.net.packet_sim import KERNEL, FlowRT, PacketSim, SimKernel
+from repro.spans import span
 
 UNSTEADY, REPLAY, PARKED = 0, 1, 2
 
@@ -240,11 +241,13 @@ class WormholeKernel(SimKernel):
             self.metric_hist[fid] = deque(maxlen=self.cfg.window)
 
         if self.cfg.enable_memo and len(fids) >= self.cfg.min_flows_memo:
-            part.fcg = self._build_fcg(part)
-            remaining = [sim.flows[fid].remaining() for fid in part.fcg.fids]
-            hit = self.db.lookup(part.fcg, remaining, atol=2 * sim.mtu)
+            with span("repro.wormhole.memo"):
+                part.fcg = self._build_fcg(part)
+                remaining = [sim.flows[fid].remaining() for fid in part.fcg.fids]
+                hit = self.db.lookup(part.fcg, remaining, atol=2 * sim.mtu)
             if hit is not None:
-                self._apply_hit(part, hit, now)
+                with span("repro.wormhole.replay"):
+                    self._apply_hit(part, hit, now)
                 return
             part.miss = True
 

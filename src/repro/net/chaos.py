@@ -56,6 +56,7 @@ import dataclasses
 import numpy as np
 
 from repro.net.flows import FlowSpec
+from repro.net.packet_sim import kernel_span
 from repro.workload.traffic import Phase
 
 # Mouse-flow ids start far above any workload/collective allocation
@@ -242,7 +243,8 @@ class _LinkSet:
             # that backlog at the new drain rate
             sim.busy_until[lid] = now + (busy - now) * (old / self.bw)
         sim._link_bw[lid] = self.bw
-        sim.kernel.on_chaos(now, (lid,))
+        with kernel_span(sim.kernel, "repro.kernel.chaos"):
+            sim.kernel.on_chaos(now, (lid,))
 
 
 def plan_for(scenario) -> ChaosPlan | None:

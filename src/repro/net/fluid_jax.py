@@ -26,6 +26,7 @@ import numpy as np
 
 from repro import compile_cache
 from repro.net.topology import Topology
+from repro.spans import span
 
 compile_cache.enable()
 
@@ -112,30 +113,37 @@ def fluid_run(M, line, rtt0, size, bw, dt: float, steps: int,
 def fluid_converged_rates(scn: FluidScenario, dt: float | None = None,
                           steps: int = 400, use_kernel: bool = False):
     """Converged per-flow rates + convergence time estimate via the steady
-    detector over the simulated rate history."""
+    detector over the simulated rate history.
+
+    Spans: ``repro.fluid.launch`` (the inputs' transfers and the scan's
+    dispatch), ``repro.fluid.fetch`` (waits for the device and copies the
+    rate history back), ``repro.fluid.converge`` (the rest)."""
     dt = dt if dt is not None else float(np.median(scn.base_rtt))
     # transient solve: rates are the question, so flows are unbounded here
     # (completion handling stays with the caller / the event kernel)
     unbounded = np.full_like(scn.size, np.inf)
-    out = fluid_run(jnp.asarray(scn.incidence), jnp.asarray(scn.line_rate),
-                    jnp.asarray(scn.base_rtt), jnp.asarray(unbounded),
-                    jnp.asarray(scn.link_bw), dt, steps,
-                    ecn_k=scn.ecn_k, mss=scn.mss, use_kernel=use_kernel)
-    hist = np.asarray(out["rate_hist"])                # [steps, F]
-    w = max(8, steps // 10)
-    mx = hist[-w:].max(0)
-    mn = hist[-w:].min(0)
-    mean = hist[-w:].mean(0)
-    fluct = np.where(mean > 0, (mx - mn) / np.maximum(mean, 1e-9), np.inf)
-    # first step where every flow's trailing window is within 5%
-    t_conv = steps * dt
-    for t in range(w, steps):
-        win = hist[t - w:t]
-        m = win.mean(0)
-        fl = np.where(m > 0, (win.max(0) - win.min(0)) / np.maximum(m, 1e-9), np.inf)
-        if (fl < 0.05).all():
-            t_conv = t * dt
-            break
+    with span("repro.fluid.launch"):
+        out = fluid_run(jnp.asarray(scn.incidence), jnp.asarray(scn.line_rate),
+                        jnp.asarray(scn.base_rtt), jnp.asarray(unbounded),
+                        jnp.asarray(scn.link_bw), dt, steps,
+                        ecn_k=scn.ecn_k, mss=scn.mss, use_kernel=use_kernel)
+    with span("repro.fluid.fetch"):
+        hist = np.asarray(out["rate_hist"])            # [steps, F]
+    with span("repro.fluid.converge"):
+        w = max(8, steps // 10)
+        mx = hist[-w:].max(0)
+        mn = hist[-w:].min(0)
+        mean = hist[-w:].mean(0)
+        fluct = np.where(mean > 0, (mx - mn) / np.maximum(mean, 1e-9), np.inf)
+        # first step where every flow's trailing window is within 5%
+        t_conv = steps * dt
+        for t in range(w, steps):
+            win = hist[t - w:t]
+            m = win.mean(0)
+            fl = np.where(m > 0, (win.max(0) - win.min(0)) / np.maximum(m, 1e-9), np.inf)
+            if (fl < 0.05).all():
+                t_conv = t * dt
+                break
     return {"rates": mean, "fluct": fluct, "t_conv": t_conv, "hist": hist}
 
 

@@ -50,6 +50,7 @@ from repro.core.partition import PartitionIndex
 from repro.core.steady import is_steady, rate_estimate
 from repro.net.packet_sim import KERNEL, FlowRT, SimKernel
 from repro.net.sharded_sim import ShardedPacketSim
+from repro.spans import span
 
 PACKET, FLOW = "packet", "flow"
 FIDELITIES = ("packet", "auto", "flow")
@@ -370,7 +371,7 @@ class HybridKernel(SimKernel):
         sim's struct-of-arrays :class:`~repro.net.soa.FlowTable` (iteration
         order matches the historical ``{fid: path}`` dict comprehension, so
         the solver's link tie-breaks — and every downstream vrate — are
-        bit-identical to the dict-solver era)."""
+        bit-identical to the dict-solver era).  Span ``repro.hybrid.solve``."""
         sim = self.sim
         self.stats["solves"] += 1
         flows = sim.flows
@@ -378,9 +379,10 @@ class HybridKernel(SimKernel):
         # capacities mid-run, and a post-chaos demotion must solve against
         # what the port actually drains now (same float values when no
         # injector fired)
-        return sim.flow_table.solve_rates(
-            (fid for fid in part.fids if not flows[fid].done),
-            sim._link_bw)
+        with span("repro.hybrid.solve"):
+            return sim.flow_table.solve_rates(
+                (fid for fid in part.fids if not flows[fid].done),
+                sim._link_bw)
 
     # ------------------------------------------------------------------ #
     # granularity transitions

@@ -36,6 +36,7 @@ from repro.net.cca import CCA, MTU, INTInfo, make_cca
 from repro.net.flows import FlowResult, FlowSpec
 from repro.net.soa import FlowTable
 from repro.net.topology import Topology
+from repro.spans import NO_SPAN, span
 
 # event kinds
 START, SEND, ARRIVE, ACK, LOSS, SAMPLE, KERNEL, CALL = range(8)
@@ -63,6 +64,12 @@ class SimKernel:
         # a chaos injector retargeted these ports' capacities
         # (repro.net.chaos); adaptive kernels re-measure affected partitions
         ...
+
+
+def kernel_span(kernel: SimKernel, name: str):
+    """The span of one call from the sim into ``kernel``
+    (``repro.kernel.<hook>``); none for the no-op kernel."""
+    return NO_SPAN if type(kernel) is SimKernel else span(name)
 
 
 @dataclass(slots=True)
@@ -323,7 +330,8 @@ class PacketSim:
         self.results[f.fid] = FlowResult(
             fid=f.fid, start=f.start_actual, fct=t - f.start_actual,
             bytes=f.spec.size, tag=f.spec.tag)
-        self.kernel.on_flow_finish(f, t)
+        with kernel_span(self.kernel, "repro.kernel.finish"):
+            self.kernel.on_flow_finish(f, t)
         for cb in self.finish_listeners:
             cb(f, t)
 
@@ -563,7 +571,8 @@ class PacketSim:
                     self.events_processed = nev
                     self.packet_hop_events = nhop
                     self._seq = seq
-                    self.kernel.on_kernel_event(t, payload[0])
+                    with kernel_span(self.kernel, "repro.kernel.event"):
+                        self.kernel.on_kernel_event(t, payload[0])
                     seq = self._seq
                 elif kind == CALL:
                     self.events_processed = nev
@@ -608,7 +617,8 @@ class PacketSim:
             elif kind == SAMPLE:
                 self._do_sample(t)
             elif kind == KERNEL:
-                self.kernel.on_kernel_event(t, payload[0])
+                with kernel_span(self.kernel, "repro.kernel.event"):
+                    self.kernel.on_kernel_event(t, payload[0])
             elif kind == CALL:
                 payload[0](t)
 
@@ -639,7 +649,8 @@ class PacketSim:
             f.last_sample_t = t
             f.last_ack_t = t
             flows.append(f)
-        self.kernel.on_flows_start(flows)
+        with kernel_span(self.kernel, "repro.kernel.admit"):
+            self.kernel.on_flows_start(flows)
         for f in flows:
             if not f.parked and not f.send_scheduled and not f.done:
                 f.send_scheduled = True
@@ -828,7 +839,8 @@ class PacketSim:
                 f.blocked = False
                 f.send_scheduled = True
                 self.schedule(t, SEND, f.fid, f.epoch)
-        self.kernel.on_sample(t)
+        with kernel_span(self.kernel, "repro.kernel.sample"):
+            self.kernel.on_sample(t)
         self._ensure_sampler(t)
 
     # ------------------------------------------------------------------ #

@@ -47,7 +47,7 @@ from concurrent.futures import ProcessPoolExecutor
 
 from repro.core.partition import PartitionIndex
 from repro.net.packet_sim import (ACK, ARRIVE, CALL, KERNEL, LOSS, SAMPLE,
-                                  SEND, START, PacketSim)
+                                  SEND, START, PacketSim, kernel_span)
 from repro.net.soa import LaneState
 from repro.net.topology import Topology
 
@@ -260,7 +260,8 @@ class ShardedPacketSim(PacketSim):
             elif kind == SAMPLE:
                 self._do_sample(t)
             elif kind == KERNEL:
-                self.kernel.on_kernel_event(t, payload[0])
+                with kernel_span(self.kernel, "repro.kernel.event"):
+                    self.kernel.on_kernel_event(t, payload[0])
             elif kind == CALL:
                 payload[0](t)
             else:  # a packet kind can only land here through a kernel bug
