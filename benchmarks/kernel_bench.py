@@ -72,13 +72,13 @@ def fluid_vs_oracle():
 
 
 def vmapped_sweep():
-    from repro.net.fluid_jax import FluidScenario, sweep
+    from repro.net.fluid_jax import FluidScenario, converged_phase_rates
     from repro.net.topology import leaf_spine_clos
     topo = leaf_spine_clos(32, leaf_down=8, n_spines=4)
     scns = [FluidScenario.from_flows(
         topo, [(i, i, 24 + (i + j) % 4, 4e6) for i in range(8)])
         for j in range(16)]
-    t = _time(lambda: sweep(scns, dt=1e-5, steps=100))
+    t = _time(lambda: converged_phase_rates(scns, steps=100, dt=1e-5))
     return [("fluid/vmap_16_experiments", t * 1e6,
              {"per_experiment_us": round(t * 1e6 / 16, 1)})]
 

@@ -332,71 +332,81 @@ class FluidEngine(Engine):
     """DCTCP-form fluid dynamics: per-phase converged rates turn into FCT
     estimates; the phase DAG is scheduled analytically on top.  Coarse: on
     the 128-GPU GPT row (flow scale 1/256) its mean FCT error against the
-    packet oracle is 54.8%.  Cheap: there an iteration takes about 0.13 s
-    on a TPU v5e host, the packet oracle 5.8 s; ``run_batch`` evaluates a
-    whole padded sweep in one vmapped compilation (§6.1 multi-experiment
-    parallelism).
+    packet oracle is 54.8%.  A phase's rates do not depend on when it
+    starts, so ``run`` solves every phase of the iteration in one device
+    program (``converged_phase_rates``: the phases padded to one shape,
+    one transfer, one dispatch, one fetch) and then schedules the DAG on
+    the host.  Cheap: there an iteration takes about 0.04 s on a TPU v5e
+    host, the packet oracle 5.8 s; ``run_batch`` evaluates a whole padded
+    sweep in one vmapped compilation (§6.1 multi-experiment parallelism).
 
-    Each phase's ``FluidScenario.from_flows`` is the ``repro.fluid.build``
-    span; ``fluid_converged_rates`` records the rest of the host path."""
+    Each phase's ``FluidScenario.from_flows`` is a ``repro.fluid.build``
+    span; ``converged_phase_rates`` records the rest of the host path once
+    per run.  ``extras["fluid_pad_share"]`` is the share of the padded
+    incidence that is padding."""
     option_names = ("dt", "steps")
     on_device = True
 
     def run(self, scenario: Scenario, steps: int = 200, dt: float | None = None,
             **opts) -> RunResult:
-        from repro.net.fluid_jax import FluidScenario, fluid_converged_rates
+        from repro.net.fluid_jax import FluidScenario, converged_phase_rates
         with span("repro.api.build"):
             chaos_mod.check_backend(chaos_mod.plan_for(scenario), self.name)
             topo = scenario.build_topology()
             phases = scenario.build_phases()
         with span("repro.engine.loop"):
             t0 = time.perf_counter()
+            solved = [ph for ph in phases if ph.flows]
+            fluid = []
+            for ph in solved:
+                with span("repro.fluid.build"):
+                    fluid.append(FluidScenario.from_flows(
+                        topo, [(f.fid, f.src, f.dst, f.size) for f in ph.flows]))
+            rates, pad_share = (converged_phase_rates(fluid, steps=steps, dt=dt)
+                                if fluid else ([], 0.0))
+            rates = iter(rates)
             fcts: dict[int, float] = {}
             flow_bytes: dict[int, float] = {}
             tags: dict[int, str] = {}
             done_t: list[float] = [0.0] * len(phases)
-            total_steps = 0
             for i, ph in enumerate(phases):
                 start = max((done_t[d] for d in set(ph.deps)), default=0.0) + ph.compute
                 if scenario.kind == "flows":
                     start += ph.flows[0].start if ph.flows else 0.0
                 end = start
-                if ph.flows:
-                    with span("repro.fluid.build"):
-                        fs = FluidScenario.from_flows(
-                            topo, [(f.fid, f.src, f.dst, f.size) for f in ph.flows])
-                    rates = fluid_converged_rates(fs, steps=steps, dt=dt)["rates"]
-                    total_steps += steps
-                    for f, rate in zip(ph.flows, rates):
-                        fct = f.size / max(float(rate), 1e3)
-                        fcts[f.fid] = fct
-                        flow_bytes[f.fid] = f.size
-                        tags[f.fid] = f.tag
-                        end = max(end, start + fct)
+                for f, rate in zip(ph.flows, next(rates) if ph.flows else ()):
+                    fct = f.size / max(float(rate), 1e3)
+                    fcts[f.fid] = fct
+                    flow_bytes[f.fid] = f.size
+                    tags[f.fid] = f.tag
+                    end = max(end, start + fct)
                 done_t[i] = end
             wall = time.perf_counter() - t0
         with span("repro.api.collect"):
             iteration = max(done_t) if done_t else None
             return RunResult(backend=self.name, scenario=scenario.name,
                              fcts=fcts, flow_bytes=flow_bytes, tags=tags,
-                             iteration_time=iteration, events_processed=total_steps,
-                             wall_time=wall)
+                             iteration_time=iteration,
+                             events_processed=steps * len(solved),
+                             wall_time=wall,
+                             extras={"fluid_pad_share": pad_share})
 
     def run_batch(self, scenarios: list[Scenario], steps: int = 200,
                   dt: float | None = None, **opts) -> list[RunResult]:
         """Pad + vmap: one compiled program evaluates every flow scenario's
-        converged rates at once (workload scenarios fall back to a loop)."""
-        from repro.net.fluid_jax import FluidScenario, sweep_converged_rates
+        converged rates at once (workload scenarios fall back to a loop).
+        ``dt`` defaults to 1e-5 s for every scenario of the batch."""
+        from repro.net.fluid_jax import FluidScenario, converged_phase_rates
         for s in scenarios:
             chaos_mod.check_backend(chaos_mod.plan_for(s), self.name)
         if any(s.kind != "flows" for s in scenarios):
             return [self.run(s, steps=steps, dt=dt, **opts) for s in scenarios]
-        dt = dt if dt is not None else 1e-5    # vmapped path needs one shared dt
         t0 = time.perf_counter()
         fls = [FluidScenario.from_flows(
             s.build_topology(), [(f.fid, f.src, f.dst, f.size) for f in s.flows])
             for s in scenarios]
-        per_scn_rates = sweep_converged_rates(fls, dt=dt, steps=steps)
+        per_scn_rates, _ = converged_phase_rates(
+            fls, steps=steps, dt=dt if dt is not None else 1e-5)
         wall = time.perf_counter() - t0
         out = []
         for s, rates in zip(scenarios, per_scn_rates):

@@ -1,5 +1,6 @@
 """The Pallas kernels compile for a TPU v5e chip at the main path's widths
-and at the ceilings their docstrings name.
+and at the ceilings their docstrings name, and so does the fluid backend's
+one program per iteration at the 128-GPU rows' padded shapes.
 
 Nothing runs: each wrapper is lowered with ``interpret=False`` against a
 described (not attached) ``v5e:2x2`` topology and compiled by the TPU
@@ -18,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.cca_step.ops import cca_step
 from repro.kernels.maxmin.kernel import MAX_INCIDENCE_BYTES, maxmin_kernel
 from repro.kernels.steady_scan.ops import steady_scan
+from repro.net.fluid_jax import fluid_run_phases
 
 
 @pytest.fixture(scope="module")
@@ -76,3 +78,13 @@ def test_steady_scan_compiles(one_chip, F, H):
     text = _compiled_text(lambda h: steady_scan(h, 16, interpret=False),
                           one_chip, (F, H))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("P,F,L", [(28, 64, 64), (36, 128, 96)])
+def test_fluid_run_phases_compiles(one_chip, P, F, L):
+    """The fluid backend's whole iteration, padded as gpt@128 and moe@128
+    pad it, is one program the chip's compiler accepts."""
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in ((P, F, L), (P, F), (P, F), (P, F), (P, L), (P,))]
+    compiled = fluid_run_phases.lower(*args, steps=200, window=20).compile()
+    assert compiled.out_info.shape == (P, 20, F)
