@@ -1,12 +1,13 @@
 """One run of one cell: set-up, the measured window, the reference, the
 comparison and the metrics.
 
-The generator is general: a cell is its configuration (the deployment)
-and its traffic mix (the backend, its options, and the variant a seed
-draws).  The seed draws ``variant.stragglers.count`` straggler ranks whose
-compute is stretched by ``variant.stragglers.factor``; every request of
-the run simulates that one variant from fresh simulator state, through the
-users' entry ``repro.api.run``.  The loop is closed with one client: the
+The generator is general: a cell is its configuration (the deployment,
+with the stage rule it names) and its traffic mix (the backend, its
+options, and the variant a seed draws).  The seed draws
+``variant.stragglers.count`` straggler ranks whose compute is stretched by
+``variant.stragglers.factor``; every request of the run simulates that one
+variant from fresh simulator state, through the users' entry
+``repro.api.run``.  The loop is closed with one client: the
 next request starts when the previous one has returned.
 """
 from __future__ import annotations
@@ -36,7 +37,8 @@ def variant(cell: Cell, seed: int):
         * cfg["fabric"]["gpus_per_server"])
     scn = training_scenario(**cfg["preset"], chaos=[
         {"kind": "straggler", "ranks": ranks, "factor": v["factor"]}])
-    phases = deployment.training_phases(cfg, {r: v["factor"] for r in ranks})
+    phases = deployment.gpipe_phases(cfg, cell.stage_loads(cfg),
+                                     {r: v["factor"] for r in ranks})
     return scn, phases, ranks
 
 

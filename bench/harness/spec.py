@@ -7,6 +7,9 @@ lists the metrics; the files sit beside the harness:
     bench/traffic/<mix>.json        a traffic mix
     bench/limits/<cell>.json        the limits ``correct`` is judged by
     bench/metrics/<metric>.py       a metric, ``read(rec)``
+    bench/reference/<rule>.py       a stage rule, ``stage_loads(cfg)``,
+                                    where a configuration names one
+                                    under ``"stage_rule"``
 
 A name that ``BENCHMARK.json`` does not know, or whose file is missing, is
 an error: nothing falls back to a default.
@@ -18,6 +21,10 @@ import importlib.util
 import json
 import pathlib
 import re
+import sys
+from collections.abc import Callable
+
+from bench.reference import deployment
 
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 
@@ -29,7 +36,7 @@ class SpecError(ValueError):
 
 def _named_file(root: pathlib.Path, folder: str, name: str,
                 suffix: str) -> pathlib.Path:
-    if not NAME.fullmatch(name) or ".." in name:
+    if not isinstance(name, str) or not NAME.fullmatch(name) or ".." in name:
         raise SpecError(f"{name!r} is not a valid name")
     path = root / "bench" / folder / f"{name}{suffix}"
     if not path.is_file():
@@ -56,21 +63,39 @@ class Cell:
     end_to_end: list[dict]
     per_layer: list[dict]
     readers: dict                 # metric name -> read(rec)
+    # the configuration's stage rule, cfg -> list[deployment.StageLoad]
+    stage_loads: Callable = deployment.stage_loads
 
 
 def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-def load_reader(root: pathlib.Path, name: str):
-    path = _named_file(root, "metrics", name, ".py")
+def _named_function(root: pathlib.Path, folder: str, name: str,
+                    function: str):
+    path = _named_file(root, folder, name, ".py")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + re.sub(r"\W", "_", name), path)
+        f"bench_{folder}_" + re.sub(r"\W", "_", name), path)
     module = importlib.util.module_from_spec(spec)
+    # registered as an import would: a dataclass in the module looks its
+    # module up in sys.modules
+    sys.modules[spec.name] = module
     spec.loader.exec_module(module)
-    if not callable(getattr(module, "read", None)):
-        raise SpecError(f"{path.relative_to(root)} has no read(rec)")
-    return module.read
+    if not callable(getattr(module, function, None)):
+        raise SpecError(f"{path.relative_to(root)} has no {function}()")
+    return getattr(module, function)
+
+
+def load_reader(root: pathlib.Path, name: str):
+    return _named_function(root, "metrics", name, "read")
+
+
+def load_stage_rule(root: pathlib.Path, config: dict):
+    """The stage rule ``config`` names, or the Table-1 rule."""
+    if "stage_rule" not in config:
+        return deployment.stage_loads
+    return _named_function(root, "reference", config["stage_rule"],
+                           "stage_loads")
 
 
 def load_cell(root: pathlib.Path, name: str) -> Cell:
@@ -88,11 +113,12 @@ def load_cell(root: pathlib.Path, name: str) -> Cell:
                         f"listed under configs")
     end_to_end = [m for m in bench["end_to_end"] if _applies(m, name)]
     per_layer = [m for m in bench["per_layer"] if _applies(m, name)]
+    config = _json(root, "configs", w["config"])
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=_json(root, "configs", w["config"]),
+        name=name, chips=int(w["chips"]), config=config,
         mix=_json(root, "traffic", w["traffic"]),
         limits=_json(root, "limits", name),
         end_to_end=end_to_end, per_layer=per_layer,
         readers={m["name"]: load_reader(root, m["name"])
-                 for m in end_to_end + per_layer})
+                 for m in end_to_end + per_layer},
+        stage_loads=load_stage_rule(root, config))
